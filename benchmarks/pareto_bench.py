@@ -64,10 +64,10 @@ from repro.core import CNN_WORKLOADS, ChipletSpec
 from repro.core.accelerator import evaluate_accelerator_grid
 from repro.core.search import (
     OBJECTIVES,
-    _dominated_by,
     _front_of,
     codesign_config_at,
     codesign_pareto,
+    front_is_exact,
     merge_fronts,
     pareto_front,
     pareto_mask_reference,
@@ -86,7 +86,6 @@ from repro.core.sweep import (
     sweep_chunked,
 )
 from repro.core.power import evaluate_network
-from repro.core.topology import TOPOLOGIES as TOPOLOGY_FACTORIES
 from repro.env import smoke_mode
 
 ARTIFACTS = Path(__file__).resolve().parent / "artifacts"
@@ -154,40 +153,12 @@ def _best_of(fn, repeats: int = 3):
     return best, out
 
 
-def _verify_front_exact(front, points: np.ndarray, block: int = 65536) -> bool:
-    """Exact front verification against the full point cloud, streamed:
-    (a) front members are mutually non-dominated (O(k^2) brute force), and
-    (b) every point is dominated by, or exactly equal to, a front member.
-    By transitivity of dominance this is equivalent to the O(n^2) pairwise
-    brute-force reference."""
-    fp = front.points
-    if not pareto_mask_reference(fp).all():
-        return False
-    for s in range(0, points.shape[0], block):
-        p = points[s:s + block]
-        dom = _dominated_by(p, fp)
-        eq = np.zeros(p.shape[0], bool)
-        fblock = max(1, 4_000_000 // max(1, p.shape[0]))
-        for fs in range(0, fp.shape[0], fblock):
-            eq |= (fp[None, fs:fs + fblock, :] == p[:, None, :]).all(-1).any(1)
-        if not (dom | eq).all():
-            return False
-    return True
-
-
 def _scalar_sample_cps(traffic, grid, sample: int = 96) -> float:
     """configs/sec of the scalar dataclass loop on a strided grid sample."""
     idx = np.linspace(0, grid.n - 1, num=min(sample, grid.n)).astype(int)
     t0 = time.perf_counter()
     for i in idx:
-        p = grid.row_params(int(i))
-        d = grid.row_devices(int(i))
-        name = grid.row_topology(int(i))
-        if name == "trine":
-            k = int(grid.cols["n_subnetworks"][i])
-            net = TOPOLOGY_FACTORIES[name](p, n_subnetworks=k or None, d=d)
-        else:
-            net = TOPOLOGY_FACTORIES[name](p, d=d)
+        net, d = grid.row_network(int(i))
         evaluate_network(net, traffic, d)
     return idx.size / (time.perf_counter() - t0)
 
@@ -279,7 +250,7 @@ def run(csv: bool = True, smoke: bool = None) -> dict:
     net_fronts_equal = (
         np.array_equal(mono_front.points, stream_front.points)
         and np.array_equal(mono_front.indices, stream_front.indices))
-    net_front_exact = _verify_front_exact(stream_front, net_pts)
+    net_front_exact = front_is_exact(stream_front, net_pts)
     if smoke:
         net_front_exact = net_front_exact and np.array_equal(
             np.sort(stream_front.indices),
@@ -390,7 +361,7 @@ def run(csv: bool = True, smoke: bool = None) -> dict:
     cd_fronts_equal = (
         np.array_equal(cd_front.points, cd_mono_front.points)
         and np.array_equal(cd_front.indices, cd_mono_front.indices))
-    cd_front_exact = _verify_front_exact(cd_front, cd_pts)
+    cd_front_exact = front_is_exact(cd_front, cd_pts)
     if smoke:
         cd_front_exact = cd_front_exact and np.array_equal(
             np.sort(cd_front.indices),

@@ -30,6 +30,7 @@ from benchmarks import fabric_whatif       # frontier fabrics -> step time
 from benchmarks import resilience_bench    # fault model / survivability
 from benchmarks import photonic_mac_bench  # kernel microbench
 from tools import lint                     # static-analysis gate
+from repro.env import use_compile_cache
 
 ARTIFACTS = Path(__file__).resolve().parent / "artifacts"
 
@@ -203,6 +204,7 @@ def write_bench9(results: dict) -> dict:
 
 
 def main() -> None:
+    use_compile_cache()
     # set here, not at import: the smoke tests import this module in-process
     # and a module-scope flip would leak float64 into the whole test run
     jax.config.update("jax_enable_x64", True)

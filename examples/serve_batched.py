@@ -11,6 +11,7 @@ compile-checking only.
 """
 
 import argparse
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -37,4 +38,5 @@ if __name__ == "__main__":
         "--batch", str(args.batch),
         "--prompt-len", str(args.prompt_len),
         "--max-new", str(args.max_new),
-    ], env={"PYTHONPATH": f"{REPO}/src", "PATH": "/usr/bin:/bin"}))
+    ], env=dict(os.environ, PYTHONPATH=os.pathsep.join(
+        p for p in (f"{REPO}/src", os.environ.get("PYTHONPATH")) if p))))

@@ -44,7 +44,8 @@ from repro.core.topology import (
     trine_network,
     electrical_mesh,
 )
-from repro.core.planner import plan_gateway_activation, plan_gateway_activation_arr
+from repro.core.planner import (
+    plan_gateway_activation, plan_gateway_activation_arr, snap_ties)
 from repro.core.workloads import Workload
 
 
@@ -330,7 +331,7 @@ def _accel_mix_math(cc, frac_ov, lc, nets, dev, mem_bw, mac_rate, slot_e,
     units = cc["n_units"][:, None]
     raw_passes = lc["dot_length"][None, :] / vec                # (C, L)
     passes = (jnp.maximum(raw_passes, 1.0) if relaxed
-              else jnp.ceil(raw_passes))
+              else jnp.ceil(snap_ties(raw_passes, jnp)))
     thr = jnp.where(units > 0, units * mac_rate / passes, 0.0)
     total_thr = thr.sum(0)                                      # (L,)
     slots = jnp.where(units > 0, passes * vec, jnp.inf).min(0)  # (L,)
@@ -343,7 +344,8 @@ def _accel_mix_math(cc, frac_ov, lc, nets, dev, mem_bw, mac_rate, slot_e,
         frac = frac_ov
     elif adaptive:
         demand = bytes_total / jnp.maximum(c_s, 1e-12)          # (L,)
-        n_gw = jnp.maximum(1.0, jnp.floor(nets["n_wavelengths"] / 8.0))
+        n_gw = jnp.maximum(
+            1.0, jnp.floor(snap_ties(nets["n_wavelengths"] / 8.0, jnp)))
         frac = plan_gateway_activation_arr(
             demand[None, :], nets["effective_bw_bps"][:, None] / 8.0,
             n_gw[:, None], xp=jnp)                              # (N, L)
@@ -464,7 +466,8 @@ def evaluate_accelerator_batch(
     bytes_total = lc["weight_bytes"] + lc["in_bytes"] + lc["out_bytes"]
     net = accel.network
     if accel.adaptive_gateways:
-        passes = np.ceil(lc["dot_length"][:, None] / cc["vector_size"][None, :])
+        passes = np.ceil(snap_ties(
+            lc["dot_length"][:, None] / cc["vector_size"][None, :]))
         thr = cc["n_units"][None, :] * accel.mac_rate_hz / passes
         c_s = lc["n_dots"] / thr.sum(axis=1)
         demand = bytes_total / np.maximum(c_s, 1e-12)

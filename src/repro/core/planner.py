@@ -30,7 +30,7 @@ import numpy as np
 __all__ = [
     "choose_subnetworks", "choose_subnetworks_arr",
     "plan_gateway_activation", "plan_gateway_activation_arr",
-    "plan_collective_channels", "ceil_log2",
+    "plan_collective_channels", "ceil_log2", "snap_ties",
 ]
 
 if TYPE_CHECKING:  # pragma: no cover
@@ -42,6 +42,29 @@ def _asx(xp, v):
     return np.asarray(v, np.float64) if xp is np else xp.asarray(v)
 
 
+# How near a planner quantity must come to a tie (an integer, or a half for
+# round-half-even) to count as on it.  The TPU emulates float64 with about 48
+# significand bits, and its division and square root do not round as IEEE
+# does: an exact quotient such as 400e9 / 80e9 = 5 can land an ulp above 5
+# there and ceil to 6.  1e-9 is far above that error (the engine's metrics
+# agree with the host's to 5e-14 on a v5e) and far below how near any
+# quotient of the grids' axis values comes to a tie without being on it.
+TIE_RTOL = 1e-9
+
+
+def snap_ties(v, xp=np):
+    """`v` with every value within TIE_RTOL of a multiple of 1/2 moved onto
+    it, so that the ceil, floor and round taken on it decide alike on every
+    backend.  Scaling by 2 and 1/2 is exact in emulated float64 too."""
+    v = _asx(xp, v)
+    h = xp.round(v * 2.0) * 0.5
+    return xp.where(xp.abs(v - h) <= TIE_RTOL * xp.abs(h), h, v)
+
+
+_POW2_MIN = 64
+_POW2 = 2.0 ** np.arange(-_POW2_MIN, _POW2_MIN + 1)  # exact in f32 and f64
+
+
 def ceil_log2(v, xp=np):
     """Exact elementwise ceil(log2(v)) for v > 0, with zero gradient.
 
@@ -50,16 +73,22 @@ def ceil_log2(v, xp=np):
     `ceil(log2(v))` may overshoot by a whole stage precisely at the integral
     points the topology kernels care about.  frexp is exact by construction:
     v = m * 2**e with m in [0.5, 1), hence ceil(log2 v) = e, except at exact
-    powers of two where m == 0.5 and ceil(log2 v) = e - 1.  The traced path
-    wraps the input in stop_gradient — the result is piecewise constant, so
-    its gradient is zero exactly like ceil(log2(.)) would give.
+    powers of two where m == 0.5 and ceil(log2 v) = e - 1.
+
+    The traced path counts the powers of two below v instead, which is as
+    exact for 2**-64 < v <= 2**64: frexp bitcasts float64 to int64, which
+    the TPU's float64 emulation cannot compile.  It wraps the input in
+    stop_gradient — the result is piecewise constant, so its gradient is
+    zero exactly like ceil(log2(.)) would give.  Counts within TIE_RTOL of
+    an integer are taken as that integer (`snap_ties`).
     """
     if xp is np:
-        m, e = np.frexp(np.asarray(v, np.float64))
-    else:
-        import jax  # runtime import: the numpy path must stay jax-free
-        m, e = xp.frexp(jax.lax.stop_gradient(xp.asarray(v)))
-    return _asx(xp, xp.where(m == 0.5, e - 1, e))
+        m, e = np.frexp(snap_ties(v))
+        return _asx(xp, xp.where(m == 0.5, e - 1, e))
+    import jax  # runtime import: the numpy path must stay jax-free
+    v = snap_ties(jax.lax.stop_gradient(xp.asarray(v)), xp)
+    below = v[..., None] > xp.asarray(_POW2, v.dtype)
+    return _asx(xp, below.sum(-1) - _POW2_MIN)
 
 
 def choose_subnetworks_arr(n_lambda, modulation_rate_bps, n_mem_chiplets,
@@ -81,7 +110,7 @@ def choose_subnetworks_arr(n_lambda, modulation_rate_bps, n_mem_chiplets,
     Both are clamped to the gateway count."""
     wg_bw = _asx(xp, n_lambda) * _asx(xp, modulation_rate_bps)
     mem_bw = _asx(xp, n_mem_chiplets) * _asx(xp, mem_bw_bytes_per_s) * 8.0
-    k = xp.maximum(1.0, xp.ceil(mem_bw / wg_bw))
+    k = xp.maximum(1.0, xp.ceil(snap_ties(mem_bw / wg_bw, xp)))
     # power-of-two so subnet trees stay balanced (paper uses 8)
     if round_mode == "paper":
         k_pow2 = 2.0 ** xp.round(xp.log2(k))
@@ -123,7 +152,7 @@ def plan_gateway_activation_arr(demand_bytes_per_s, max_bw_bytes_per_s,
     maxbw = _asx(xp, max_bw_bytes_per_s)
     n = _asx(xp, n_gateways)
     frac = xp.clip(demand / xp.where(maxbw > 0, maxbw, np.inf), 0.0, 1.0)
-    steps = xp.maximum(1.0, xp.ceil(frac * n))
+    steps = xp.maximum(1.0, xp.ceil(snap_ties(frac * n, xp)))
     return xp.where(maxbw > 0, steps / n, 1.0)
 
 

@@ -24,9 +24,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from jax.experimental import enable_x64 as _enable_x64
-
 from repro.core.devices import DeviceLibrary, DEFAULT_DEVICES, laser_electrical_power_w
+from repro.core.planner import snap_ties
 from repro.core.topology import NetworkModel
 
 
@@ -43,7 +42,7 @@ def engine_x64():
     counts) one rounding error away from flipping between grid rows.  The
     flag is thread-local, so pipeline worker threads must enter their own
     context — `core.sweep` does this at every fold/enqueue site."""
-    return _enable_x64()
+    return jax.enable_x64(True)
 
 # metric columns `eval_network_math` emits == NetworkReport fields — the
 # network-side metric vocabulary.  `core.sweep.METRIC_FIELDS` aliases this,
@@ -106,8 +105,10 @@ def eval_network_math(nets: Dict[str, jax.Array], dev: Dict[str, jax.Array],
     wavelength/bank quantization is piecewise-constant — zero gradient)."""
     # ---- photonic ----
     frac = jnp.clip(active_fraction, 1e-3, 1.0)
-    n_lambda_active = jnp.maximum(1.0, jnp.round(nets["n_wavelengths"] * frac))
-    n_banks_active = jnp.maximum(1.0, jnp.round(nets["n_laser_banks"] * frac))
+    n_lambda_active = jnp.maximum(
+        1.0, jnp.round(snap_ties(nets["n_wavelengths"] * frac, jnp)))
+    n_banks_active = jnp.maximum(
+        1.0, jnp.round(snap_ties(nets["n_laser_banks"] * frac, jnp)))
     p_tx_dbm = (dev["pd.sensitivity_dbm"] + dev["laser.power_margin_db"]
                 + nets["worst_path_loss_db"] + dev["laser.coupling_loss_db"])
     per_lambda_w = 1e-3 * 10.0 ** (p_tx_dbm / 10.0)
@@ -191,9 +192,9 @@ def evaluate_network(
         )
 
     frac = float(np.clip(active_fraction, 1e-3, 1.0))
-    n_lambda_active = max(1, int(round(net.n_wavelengths * frac)))
+    n_lambda_active = max(1, round(float(snap_ties(net.n_wavelengths * frac))))
 
-    n_banks_active = max(1, int(round(net.n_laser_banks * frac)))
+    n_banks_active = max(1, round(float(snap_ties(net.n_laser_banks * frac))))
     laser_p = float(
         laser_electrical_power_w(
             net.worst_path_loss_db, n_lambda_active, d, n_banks=n_banks_active

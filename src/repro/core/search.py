@@ -108,7 +108,7 @@ from repro.core.workloads import Workload
 
 __all__ = [
     "OBJECTIVES", "pareto_mask", "pareto_mask_reference", "ParetoFront",
-    "merge_fronts", "pareto_front", "ParetoReducer", "pareto_search",
+    "front_is_exact", "merge_fronts", "pareto_front", "ParetoReducer", "pareto_search",
     "codesign_pareto", "codesign_config_at", "frontier_configs",
     "refine_continuous", "refine_front_point", "DEFAULT_REFINE_AXES",
     "refine_codesign", "refine_trust_region", "refine_front",
@@ -331,6 +331,28 @@ def _front_of(points: np.ndarray, indices: np.ndarray,
             idx_b = np.concatenate([front.indices, idx_b[keep]], axis=0)
         front = _front_exact(pts_b, idx_b, objectives)
     return front
+
+
+def front_is_exact(front: ParetoFront, points: np.ndarray,
+                   block: int = 65536) -> bool:
+    """Exact front verification against the full point cloud, streamed:
+    (a) front members are mutually non-dominated (O(k^2) brute force), and
+    (b) every point is dominated by, or exactly equal to, a front member.
+    By transitivity of dominance this is equivalent to the O(n^2) pairwise
+    brute-force reference."""
+    fp = front.points
+    if not pareto_mask_reference(fp).all():
+        return False
+    for s in range(0, points.shape[0], block):
+        p = points[s:s + block]
+        dom = _dominated_by(p, fp)
+        eq = np.zeros(p.shape[0], bool)
+        fblock = max(1, 4_000_000 // max(1, p.shape[0]))
+        for fs in range(0, fp.shape[0], fblock):
+            eq |= (fp[None, fs:fs + fblock, :] == p[:, None, :]).all(-1).any(1)
+        if not (dom | eq).all():
+            return False
+    return True
 
 
 def merge_fronts(*fronts: ParetoFront) -> ParetoFront:

@@ -200,13 +200,24 @@ class GridSpec:
         """(cols, topo_id) for flat rows [start, stop) — element-for-element
         the values eager `build_grid` places at those rows.  The golden host
         reference the jitted decode program is parity-tested against."""
-        idx = np.arange(start, stop)
+        return self._cols_at(np.arange(start, stop))
+
+    def _cols_at(self, idx: np.ndarray
+                 ) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
         digits = np.unravel_index(idx, self.shape)
         cols = {name: np.full(idx.size, v, np.float64)
                 for name, v in self.base.items()}
         for ai, (name, vals) in enumerate(self.axes.items()):
             cols[name] = np.asarray(vals, np.float64)[digits[1 + ai]]
         return cols, np.ascontiguousarray(digits[0])
+
+    def rows(self, idx) -> "SweepGrid":
+        """Eager SweepGrid of the flat rows `idx` (any order): row j of the
+        result is grid row idx[j]."""
+        idx = np.asarray(idx, np.int64)
+        cols, topo_id = self._cols_at(idx)
+        return SweepGrid(topologies=self.topologies, axes=self.axes,
+                         cols=cols, topo_id=topo_id, shape=(idx.size,))
 
     def config_at(self, i: int) -> Dict[str, float]:
         """Human-readable swept-axis settings of flat row `i`."""
@@ -302,6 +313,16 @@ class SweepGrid:
 
     def row_topology(self, i: int) -> str:
         return self.topologies[int(self.topo_id[i])]
+
+    def row_network(self, i: int, base: Optional[DeviceLibrary] = None):
+        """(NetworkModel, DeviceLibrary) of flat row `i` built through the
+        scalar dataclass path — the golden host reference for the row."""
+        p, d = self.row_params(i), self.row_devices(i, base)
+        name = self.row_topology(i)
+        if name == "trine":
+            k = int(self.cols["n_subnetworks"][i])
+            return TOPOLOGIES[name](p, n_subnetworks=k or None, d=d), d
+        return TOPOLOGIES[name](p, d=d), d
 
 
 def build_grid(
@@ -907,14 +928,7 @@ def sweep_scalar_reference(
     base = devices or DEFAULT_DEVICES
     out = {k: np.zeros(grid.n, np.float64) for k in METRIC_FIELDS}
     for i in range(grid.n):
-        p = grid.row_params(i)
-        d = grid.row_devices(i, base)
-        name = grid.row_topology(i)
-        if name == "trine":
-            k = int(grid.cols["n_subnetworks"][i])
-            net = TOPOLOGIES[name](p, n_subnetworks=k or None, d=d)
-        else:
-            net = TOPOLOGIES[name](p, d=d)
+        net, d = grid.row_network(i, base)
         rep = evaluate_network(net, traffic, d, active_fraction=active_fraction)
         for key in METRIC_FIELDS:
             out[key][i] = getattr(rep, key)

@@ -43,7 +43,7 @@ from typing import Callable, Dict, Mapping, Optional
 import numpy as np
 
 from repro.core.devices import DeviceLibrary, DEFAULT_DEVICES, device_columns
-from repro.core.planner import ceil_log2, choose_subnetworks_arr
+from repro.core.planner import ceil_log2, choose_subnetworks_arr, snap_ties
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,12 +166,12 @@ def spacx_bus_arrays(c: ColumnMap, xp=np) -> Dict[str, np.ndarray]:
     fewer rings sit on any path (lower loss than SPRINT) at the cost of fewer
     concurrently-usable wavelengths (BW partitioned by cluster)."""
     cluster = 8.0
-    if xp is np and np.any(np.asarray(c["n_gateways"]) < cluster):
+    if xp is np and np.any(snap_ties(c["n_gateways"]) < cluster):
         # data-dependent validation only on the concrete (numpy) path; under
         # jax tracing the caller is responsible for a valid grid
         raise ValueError("SPACX requires n_gateways >= 8 (one full cluster); "
                          "smaller values would leave zero usable waveguides")
-    n_clusters = xp.floor(c["n_gateways"] / cluster)
+    n_clusters = xp.floor(snap_ties(c["n_gateways"] / cluster, xp))
     through = (cluster - 1) * 2 * c["n_lambda"] * c["mr.through_loss_db"]
     prop = 1.5 * c["interposer_side_cm"] * c["wg.propagation_loss_db_per_cm"]
     loss = through + prop + c["mr.drop_loss_db"] + c["mr.modulation_loss_db"]
@@ -228,7 +228,7 @@ def trine_network_arrays(c: ColumnMap, xp=np) -> Dict[str, np.ndarray]:
         c["mem_bw_bytes_per_s"], g, xp=xp)
     k_over = _asx(xp, c.get("n_subnetworks", 0.0))
     k = xp.where(k_over > 0, k_over, k_auto)
-    per = xp.maximum(1.0, xp.floor(g / k))
+    per = xp.maximum(1.0, xp.floor(snap_ties(g / k, xp)))
     stages = xp.maximum(1.0, ceil_log2(per, xp))
     prop = (c["interposer_side_cm"] / 3) * c["wg.propagation_loss_db_per_cm"]  # shorter subnet spans
     loss = (stages * c["mzi.insertion_loss_db"] + prop
@@ -257,7 +257,7 @@ def electrical_mesh_arrays(c: ColumnMap, xp=np) -> Dict[str, np.ndarray]:
     """Electrical 2D-mesh interposer NoC baseline (DeFT [21]), used by the
     2.5D-CrossLight-Elec-Interposer variant in Sec. V."""
     n = c["n_gateways"] + c["n_mem_chiplets"]
-    side = xp.ceil(xp.sqrt(n))
+    side = xp.ceil(snap_ties(xp.sqrt(n), xp))
     avg_hops = 2 * side / 3  # uniform-random average Manhattan distance
     hop_cm = c["interposer_side_cm"] / side
     per_hop_s = (c["elec.router_latency_s"]

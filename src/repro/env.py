@@ -1,6 +1,6 @@
 """Environment flags shared by benchmarks, examples, and tests.
 
-Two knobs are recognized:
+Three variables are recognized:
 
   REPRO_SMOKE     truthy -> tiny-grid / few-step CI smoke runs.
   REPRO_PREFETCH  integer >= 0 -> streaming-pipeline prefetch depth for the
@@ -10,11 +10,15 @@ Two knobs are recognized:
                   one chunk computing while the previous one folds —
                   double-buffering.  Any depth produces bit-identical reducer
                   states; the knob only trades memory for overlap.
+  JAX_COMPILATION_CACHE_DIR  where `use_compile_cache` keeps JAX's
+                  persistent compilation cache (read by JAX itself); unset
+                  -> `<checkout>/.jax_cache`.
 """
 
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
 _TRUTHY = ("1", "true", "yes", "on")
 
@@ -50,3 +54,23 @@ def prefetch_depth(default: int = DEFAULT_PREFETCH) -> int:
         return max(0, int(raw.strip()))
     except ValueError:
         return default
+
+
+# fixed, so that one checkout's runs find each other's compiled programs
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Entry points call this before their first compile.  Where
+    JAX_COMPILATION_CACHE_DIR is set, JAX already reads it and nothing else
+    is set here; otherwise the cache lives at the checkout's fixed
+    `.jax_cache` (never a temporary, per-process or timed path: the path is
+    part of what makes a later run hit)."""
+    raw = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if raw:
+        return raw
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
+from repro.kernels.flash_attention import DEFAULT_BQ as BQ
 from repro.kernels.flash_attention import flash_attention as _flash_fwd
 from repro.kernels.photonic_mac import (
     photonic_mac as _mac_fwd,
@@ -29,6 +30,7 @@ from repro.kernels.photonic_mac import (
     DEFAULT_BK,
     DEFAULT_BN,
 )
+from repro.kernels.ssm_scan import DEFAULT_CHUNK as SSM_CHUNK
 from repro.kernels.ssm_scan import ssm_scan as _ssm_fwd
 
 
@@ -107,6 +109,15 @@ def attention(q, k, v, causal: bool = True, window: int = 0,
 
 def _attention_impl(q, k, v, causal, window, scale, q_offset, use_kernel):
     sq, sk = q.shape[2], k.shape[2]
+    if use_kernel and causal and q_offset == 0 and sq == sk > BQ and sq % BQ:
+        # causal self-attention at a ragged length (exact-length prefill):
+        # pad to the block, where the causal mask hides the padded keys from
+        # every real query, and slice the padded query rows off
+        pad = ((0, 0), (0, 0), (0, -sq % BQ), (0, 0))
+        out = _flash_fwd(jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
+                         causal=True, window=window, scale=scale,
+                         interpret=_on_cpu())
+        return out[:, :, :sq]
     tileable = (
         use_kernel
         and sq % min(128, sq) == 0
@@ -153,8 +164,15 @@ def ssm(x, a, b, c, use_kernel: bool = True):
 
 def _ssm_impl(x, a, b, c, use_kernel):
     l = x.shape[1]
-    if use_kernel and l % min(128, l) == 0 and l >= 8:
-        return _ssm_fwd(x, a, b, c, interpret=_on_cpu())
+    if use_kernel and l >= 8:
+        # a ragged length pads to the chunk with zero inputs and unit decay:
+        # the scan is causal, so the padded tail never reaches a real output
+        tail = -l % SSM_CHUNK if l > SSM_CHUNK else 0
+        if tail:
+            x, b, c = (jnp.pad(t, ((0, 0), (0, tail), (0, 0)))
+                       for t in (x, b, c))
+            a = jnp.pad(a, ((0, 0), (0, tail)), constant_values=1.0)
+        return _ssm_fwd(x, a, b, c, interpret=_on_cpu())[:, :l]
     # XLA fallback = the same chunked SSD algorithm (L/chunk trips, MXU-shaped
     # dots), NOT the sequential oracle — §Perf zamba2 iteration 2
     return _ref.ssm_scan_chunked_ref(x, a, b, c)

@@ -46,7 +46,7 @@ def _mac_kernel(x_ref, wq_ref, scale_ref, o_ref, acc_ref, *, n_k: int):
 
     x = x_ref[...].astype(jnp.float32)
     # dequantize this weight-bank tile: int levels * per-tile scale
-    w = wq_ref[...].astype(jnp.float32) * scale_ref[0, 0]
+    w = wq_ref[...].astype(jnp.float32) * scale_ref[...]
     acc_ref[...] += jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -96,13 +96,15 @@ def photonic_mac(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (kk, j)),
+            # one scale per weight-bank tile; the trailing (1, 1) dims equal
+            # the array's, which the TPU's (8, 128) block tiling rule admits
+            pl.BlockSpec((None, None, 1, 1), lambda i, j, kk: (kk, j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, w_q, w_scale)
+    )(x, w_q, w_scale.reshape(n_k, n_j, 1, 1))
     return out if (mp, np_) == (m, n) else out[:m, :n]
 
 

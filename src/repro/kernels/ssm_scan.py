@@ -28,7 +28,8 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_CHUNK = 128
 
 
-def _ssm_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, s_ref, *, chunk: int):
+def _ssm_kernel(x_ref, cum_col_ref, cum_row_ref, last_ref, b_ref, c_ref,
+                y_ref, s_ref, *, chunk: int):
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
@@ -36,21 +37,19 @@ def _ssm_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, s_ref, *, chunk: int):
         s_ref[...] = jnp.zeros_like(s_ref)
 
     x = x_ref[0].astype(jnp.float32)            # (C, P)
-    a = a_ref[0].astype(jnp.float32)            # (C,)
+    cum_c = cum_col_ref[0]                      # (C, 1) inclusive cumprod
+    cum_r = cum_row_ref[0]                      # (1, C) the same, as a row
     b = b_ref[0].astype(jnp.float32)            # (C, N)
     c = c_ref[0].astype(jnp.float32)            # (C, N)
-
-    log_a = jnp.log(jnp.maximum(a, 1e-37))
-    cum = jnp.exp(jnp.cumsum(log_a))            # (C,) inclusive cumprod
     s_in = s_ref[...]                           # (P, N)
 
     # carry-in contribution: y_carry[t] = cum_t * (c_t @ S_in^T)
-    y_carry = cum[:, None] * jax.lax.dot_general(
+    y_carry = cum_c * jax.lax.dot_general(
         c, s_in, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                           # (C, P)
 
     # intra-chunk: decay matrix M[s,t] = cum_t / cum_s for s <= t
-    ratio = cum[None, :] / jnp.maximum(cum[:, None], 1e-37)
+    ratio = cum_r / jnp.maximum(cum_c, 1e-37)
     st_mask = (
         jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
         <= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
@@ -66,9 +65,10 @@ def _ssm_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, s_ref, *, chunk: int):
     y_ref[0] = (y_carry + y_intra).astype(y_ref.dtype)
 
     # state update: S_out = cum_C S_in + sum_s (cum_C / cum_s) x_s b_s^T
-    wgt = cum[-1] / jnp.maximum(cum, 1e-37)     # (C,)
-    s_ref[...] = cum[-1] * s_in + jax.lax.dot_general(
-        x * wgt[:, None], b, (((0,), (0,)), ((), ())),
+    last = last_ref[...]                        # (1, 1) cum_C
+    wgt = last / jnp.maximum(cum_c, 1e-37)      # (C, 1)
+    s_ref[...] = last * s_in + jax.lax.dot_general(
+        x * wgt, b, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
@@ -84,12 +84,24 @@ def ssm_scan(
     assert l % ch == 0, (l, ch)
     assert a.shape == (bh, l) and b.shape == (bh, l, n) and c.shape == (bh, l, n)
 
+    # within-chunk inclusive cumprod of the decay, in log space (f32).  The
+    # kernel reads it as a column, as a row and (its last entry) as a
+    # (1, 1) block per chunk: Mosaic has no in-kernel scan, and cannot
+    # broadcast a (1, 1) slice over sublanes and lanes at once
+    nc = l // ch
+    log_a = jnp.log(jnp.maximum(a.astype(jnp.float32), 1e-37))
+    cum = jnp.exp(jnp.cumsum(log_a.reshape(bh, nc, ch), axis=-1))
+    last = cum[:, :, -1:, None]                                 # (BH,nc,1,1)
+    cum = cum.reshape(bh, l)
+
     return pl.pallas_call(
         functools.partial(_ssm_kernel, chunk=ch),
         grid=(bh, l // ch),
         in_specs=[
             pl.BlockSpec((1, ch, p), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, ch), lambda i, j: (i, j)),
+            pl.BlockSpec((1, ch, 1), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, 1, ch), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((None, None, 1, 1), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((1, ch, n), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, ch, n), lambda i, j: (i, j, 0)),
         ],
@@ -97,4 +109,4 @@ def ssm_scan(
         out_shape=jax.ShapeDtypeStruct((bh, l, p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, a, b, c)
+    )(x, cum[:, :, None], cum[:, None, :], last, b, c)

@@ -17,10 +17,12 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs as C
+from repro.env import use_compile_cache
 from repro.models import model as M
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

@@ -15,12 +15,14 @@ import argparse
 
 from repro import configs as C
 from repro.data.pipeline import DataConfig
+from repro.env import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.optim.adamw import OptConfig
 from repro.runtime.trainer import Trainer, TrainerConfig
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",

@@ -18,7 +18,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.kernels import ops
 from repro.models.config import ModelConfig
 from repro.parallel import actx
@@ -385,7 +384,7 @@ def apply_moe(cfg: ModelConfig, p: Params, x: jax.Array):
             from jax.sharding import PartitionSpec as _P
             b3 = _P(dpt, None, None)
             b2 = _P(dpt, None)
-            y = shard_map(
+            y = jax.shard_map(
                 lambda pw, xn_, idx_, gv_, kp_, pc_: _moe_index_path(
                     cfg, pw, xn_, idx_, gv_, kp_, pc_, cap),
                 mesh=mesh,

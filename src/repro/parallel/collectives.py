@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 from repro.core.planner import plan_collective_channels as plan_channels  # noqa: F401 — re-export
 
@@ -48,7 +47,7 @@ def flat_all_reduce(x: jax.Array, mesh: Mesh, axes: Tuple[str, ...] = ("pod", "d
     def f(v):
         return jax.lax.psum(v, axes)
 
-    return shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+    return jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
                      check_vma=False)(x)
 
 
@@ -68,7 +67,7 @@ def trine_all_reduce(x: jax.Array, mesh: Mesh):
         full = jax.lax.all_gather(piece, "data", axis=0, tiled=True)
         return full[:orig].reshape(flatshape)
 
-    return shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+    return jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
                      check_vma=False)(x)
 
 
@@ -149,7 +148,7 @@ def compressed_all_reduce(
         return (full[:orig].reshape(flatshape),
                 res_full[:orig].reshape(flatshape))
 
-    out, new_res = shard_map(
+    out, new_res = jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
         check_vma=False,
     )(x, residual)

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def split_stages(stacked_params, n_stages: int):
@@ -122,7 +121,7 @@ def pipelined_apply(
         outs = jnp.where(stage == n_stages - 1, outs, jnp.zeros_like(outs))
         return jax.lax.psum(outs, axis)
 
-    return shard_map(run, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+    return jax.shard_map(run, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                      check_vma=False)(stage_params, x)
 
 

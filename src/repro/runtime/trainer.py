@@ -114,7 +114,9 @@ def build_sharded_step(cfg: ModelConfig, opt: adamw.OptConfig, mesh,
     step_fn = make_train_step(cfg, opt, param_wire=pw)
     state_sh = S.tree_shardings(mesh, adamw.state_specs(param_specs), rules)
     batch_sh = S.train_batch_shardings(cfg, mesh, batch_example)
+    # the new state keeps the declared layout, so it can be fed back in
     return jax.jit(step_fn, in_shardings=(state_sh, batch_sh),
+                   out_shardings=(state_sh, None),
                    donate_argnums=(0,)), state_sh, batch_sh
 
 

@@ -1,25 +1,6 @@
 """Shared pytest config.  NOTE: no XLA_FLAGS here — smoke tests and benches
 must see the 1 real CPU device; multi-device tests use subprocesses.
-
-If the real `hypothesis` package is missing (the container image does not
-bake it in), register the deterministic stub in tests/_hypothesis_stub.py
-under the same module name before any test module imports it.
 """
-import importlib.util
-import sys
-from pathlib import Path
-
-import pytest
-
-try:  # pragma: no cover - depends on the environment image
-    import hypothesis  # noqa: F401
-except ImportError:
-    _stub_path = Path(__file__).resolve().parent / "_hypothesis_stub.py"
-    _spec = importlib.util.spec_from_file_location("hypothesis", _stub_path)
-    _mod = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _mod.strategies
 
 
 def pytest_configure(config):

@@ -152,3 +152,45 @@ def test_multidevice_suite(tmp_path):
                    "OK sharding rules all archs",
                    "OK sharded train step + hlo analysis"):
         assert marker in r.stdout
+
+
+TRAINER_SCRIPT = textwrap.dedent("""
+    import os, tempfile
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, numpy as np
+    from repro import configs as C
+    from repro.data.pipeline import DataConfig
+    from repro.launch.mesh import make_test_mesh
+    from repro.optim.adamw import OptConfig
+    from repro.runtime.trainer import Trainer, TrainerConfig
+
+    cfg = C.get_reduced("zamba2-1.2b")
+    losses = {}
+    for label, mesh in (("mesh", make_test_mesh(data=2, model=2)),
+                        ("one", None)):
+        with tempfile.TemporaryDirectory() as d:
+            tr = Trainer(cfg, OptConfig(), DataConfig(global_batch=4,
+                                                      seq_len=64),
+                         TrainerConfig(ckpt_dir=d), mesh=mesh, resume=False)
+            tr.run(3, quiet=True)
+            losses[label] = [h["loss"] for h in tr.history]
+            if mesh is not None:
+                for leaf, sh in zip(jax.tree.leaves(tr.state),
+                                    jax.tree.leaves(tr.state_sh)):
+                    assert leaf.sharding == sh, (leaf.sharding, sh)
+    np.testing.assert_allclose(losses["mesh"], losses["one"], rtol=1e-4)
+    print("OK trainer steps on a 2x2 mesh")
+""")
+
+
+def test_trainer_steps_on_mesh_keep_state_sharding():
+    """Three Trainer steps on a 2x2 (data, model) mesh: every step's state
+    comes back in its declared sharding (so it can be fed to the next
+    step), and the losses match the same steps on one device."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = f"{REPO}/src"
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", TRAINER_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-3000:]}"
+    assert "OK trainer steps on a 2x2 mesh" in r.stdout

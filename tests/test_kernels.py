@@ -135,7 +135,8 @@ def test_wire_grads_close_to_master():
     from repro.parallel import sharding as S
     CFG = C.get_reduced("yi_6b")
     params, specs = M.init(CFG, jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = S.rules_for(CFG, mesh)
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, CFG.vocab),
              "labels": jax.random.randint(jax.random.PRNGKey(2), (2, 64), 0, CFG.vocab)}
@@ -198,6 +199,26 @@ def test_flash_attention_noncausal():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("s,window", [(200, 0), (1023, 0), (300, 64)])
+def test_attention_ragged_causal_length_runs_kernel(s, window, monkeypatch):
+    """A causal self-attention of a length that is not a multiple of the
+    block (exact-length prefill) runs the kernel on a padded copy and
+    matches the oracle on the real rows."""
+    calls = []
+    real = ops._flash_fwd
+    monkeypatch.setattr(ops, "_flash_fwd",
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    ks = jax.random.split(jax.random.PRNGKey(s + window), 3)
+    q = jax.random.normal(ks[0], (1, 4, s, 32), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 2, s, 32), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 2, s, 32), jnp.float32)
+    out = ops.attention(q, k, v, True, window, None, 0, True)
+    exp = ref.attention_ref(q, k, v, causal=True, window=window)
+    assert calls and out.shape == exp.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                               rtol=2e-5, atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # ssm scan
 # ---------------------------------------------------------------------------
@@ -213,6 +234,26 @@ def test_ssm_scan_matches_oracle(bh, l, p, n):
     c = jax.random.normal(ks[3], (bh, l, n)) * 0.3
     out = ssm_scan(x, a, b, c, interpret=True)
     exp = ref.ssm_scan_ref(x, a, b, c)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("l", [200, 1023])
+def test_ssm_ragged_length_runs_kernel(l, monkeypatch):
+    """A scan length that is not a multiple of the chunk runs the kernel on
+    a zero-input, unit-decay padded tail and matches the oracle."""
+    calls = []
+    real = ops._ssm_fwd
+    monkeypatch.setattr(ops, "_ssm_fwd",
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    ks = jax.random.split(jax.random.PRNGKey(l), 4)
+    x = jax.random.normal(ks[0], (2, l, 16)) * 0.5
+    a = jax.nn.sigmoid(jax.random.normal(ks[1], (2, l))) * 0.3 + 0.68
+    b = jax.random.normal(ks[2], (2, l, 8)) * 0.3
+    c = jax.random.normal(ks[3], (2, l, 8)) * 0.3
+    out = ops.ssm(x, a, b, c, True)
+    exp = ref.ssm_scan_ref(x, a, b, c)
+    assert calls and out.shape == exp.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=2e-4, atol=2e-4)
 

@@ -74,7 +74,8 @@ SCRIPT = textwrap.dedent("""
     import jax.numpy as jnp
     from repro.parallel import pipeline as PP
 
-    mesh = jax.make_mesh((4, 2), ("pipe", "data"))
+    mesh = jax.make_mesh((4, 2), ("pipe", "data"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     # stage = 2-layer MLP stack; stage params leaves (S, L, ...)
     S, L, D, M, MB = 4, 2, 16, 6, 8

@@ -139,7 +139,8 @@ def test_wire_format_training_converges(tmp_path):
 
     # single-device: the sharding constraint inside wire needs a mesh, so
     # emulate the numerics-only path with a trivial 1x1 mesh
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     from repro.parallel import sharding as S
     rules = S.rules_for(cfg8, mesh)
     pw = W.make_param_wire(cfg8, mesh, rules, specs)

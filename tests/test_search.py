@@ -398,15 +398,13 @@ def test_relaxed_accel_grad_matches_finite_differences(axis, j, x0):
     count) equals float64 central finite differences of the same relaxed
     function, for every relaxable accelerator axis — mirroring the network-
     axis gradient checks above."""
-    from jax.experimental import enable_x64
-
     def loss(theta):
         return _relaxed_accel_log_edp(axis, j, jnp.exp(theta))
 
     theta0 = float(np.log(x0))
     g = float(jax.grad(loss)(jnp.asarray(theta0, jnp.float32)))
     h = 0.02
-    with enable_x64():
+    with jax.enable_x64(True):
         f_hi = float(loss(jnp.asarray(theta0 + h, jnp.float64)))
         f_lo = float(loss(jnp.asarray(theta0 - h, jnp.float64)))
     fd = (f_hi - f_lo) / (2 * h)

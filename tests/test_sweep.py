@@ -17,6 +17,7 @@ from repro.core import (
     crosslight_25d_siph,
     evaluate_accelerator,
     evaluate_accelerator_batch,
+    evaluate_accelerator_grid,
     evaluate_network,
     monolithic_crosslight,
     trine_network,
@@ -25,6 +26,7 @@ from repro.core import (
 from repro.core.sweep import (
     DEFAULT_TOPOLOGIES,
     METRIC_FIELDS,
+    _network_columns_arrays,
     build_grid,
     evaluate_columns,
     network_columns,
@@ -138,6 +140,67 @@ def test_spacx_rejects_subcluster_gateway_counts():
     from repro.core import spacx_bus
     with pytest.raises(ValueError):
         spacx_bus(NetworkParams(n_gateways=4))
+
+
+# The TPU emulates float64 with about 48 significand bits, and its division
+# and square root do not round as IEEE does: a quotient that is exactly an
+# integer on the host (400e9 / 80e9 = 5) can land an ulp above it there, and
+# its ceil would take the next count.  Nudging every input by a few such
+# ulps stands in for that backend on the CPU.
+NUDGE = 1e-13
+
+# axes on which the planner's quotients and products sit exactly on ties
+TIE_AXES = dict(n_gateways=(8, 16, 32), n_lambda=(4, 8),
+                mem_bw_bytes_per_s=(25e9, 50e9, 100e9),
+                modulation_rate_bps=(10e9, 12e9), n_mem_chiplets=(2, 4))
+
+
+def _nudge(v, rng):
+    v = np.asarray(v, np.float64)
+    return v * (1 + NUDGE * rng.choice([-1.0, 1.0], v.shape))
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_network_counts_survive_last_bit_error(seed):
+    """Subnetwork, stage, cluster, mesh-side and lit-wavelength counts are
+    taken on exact ties across this grid (3/8 of 4 wavelengths is 1.5); a
+    few ulps of error in every input must move none of them, so no metric
+    moves beyond rounding."""
+    grid = build_grid(DEFAULT_TOPOLOGIES, **TIE_AXES)
+    frac = np.full(grid.n, 0.375)
+    exact = evaluate_columns(network_columns(grid), grid.cols,
+                             TRAFFIC.total_bits, TRAFFIC.n_transfers, frac)
+    rng = np.random.default_rng(seed)
+    cols = {k: _nudge(v, rng) for k, v in grid.cols.items()}
+    nets = _network_columns_arrays(cols, grid.topo_id, grid.topologies)
+    got = evaluate_columns(nets, cols, TRAFFIC.total_bits,
+                           TRAFFIC.n_transfers, _nudge(frac, rng))
+    for k in METRIC_FIELDS:
+        np.testing.assert_allclose(got[k], exact[k], rtol=1e-10, err_msg=k)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_accelerator_counts_survive_last_bit_error(seed):
+    """The co-design kernel's pass counts (ceil of dot length over vector
+    size), gateway counts and activation steps hold under the same nudge."""
+    wl = CNN_WORKLOADS["ResNet18"]()
+    grid = build_grid(("sprint", "spacx", "trine"), **TIE_AXES)
+    mixes = [crosslight_25d_siph().chiplets, crosslight_25d_elec().chiplets]
+
+    def score(mixes, nets, cols):
+        mem_bw = cols["n_mem_chiplets"] * cols["mem_bw_bytes_per_s"]
+        return evaluate_accelerator_grid(wl, mixes, nets, cols, mem_bw)
+
+    exact = score(mixes, network_columns(grid), grid.cols)
+    rng = np.random.default_rng(seed)
+    cols = {k: _nudge(v, rng) for k, v in grid.cols.items()}
+    nets = _network_columns_arrays(cols, grid.topo_id, grid.topologies)
+    mixes = [[dataclasses.replace(c, vector_size=float(_nudge(c.vector_size,
+                                                              rng)))
+              for c in mix] for mix in mixes]
+    got = score(mixes, nets, cols)
+    for k in ("latency_s", "power_w", "energy_j", "compute_s", "network_s"):
+        np.testing.assert_allclose(got[k], exact[k], rtol=1e-10, err_msg=k)
 
 
 @pytest.mark.parametrize("accel_factory", [
