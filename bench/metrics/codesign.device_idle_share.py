@@ -1,0 +1,6 @@
+"""`engine.device_idle_share`, read in the co-design cell, where it moves
+`joint_designs_per_s`."""
+
+from benchlib.harness import BENCH, load_file_module
+
+read = load_file_module(BENCH / "metrics" / "engine.device_idle_share.py").read

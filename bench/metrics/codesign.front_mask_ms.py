@@ -1,0 +1,6 @@
+"""`engine.front_mask_ms`, read in the co-design cell, where it moves
+`joint_designs_per_s`."""
+
+from benchlib.harness import BENCH, load_file_module
+
+read = load_file_module(BENCH / "metrics" / "engine.front_mask_ms.py").read

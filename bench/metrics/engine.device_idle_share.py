@@ -1,0 +1,8 @@
+"""Share of the traced searches in which no program ran on the chip (%).
+
+1 - (union of device busy intervals) / (traced window), from the trace.
+"""
+
+
+def read(win, cell):
+    return 100.0 * win.idle_share
