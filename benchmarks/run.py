@@ -4,8 +4,6 @@ consolidated ``artifacts/summary.json`` with every benchmark's checks and
 the cross-benchmark perf-regression gates (batched >= 20x scalar, chunked
 within 1.5x of monolithic, device-pipelined streaming >= 1.2x host-serial on
 the full-mode grid — smoke runs use each benchmark's recorded smoke bar).
-Also writes ``artifacts/BENCH_9.json``, the perf-trajectory artifact for the
-streaming engine (configs/sec by path, overlap gains, grid sizes).
 
   PYTHONPATH=src:. python -m benchmarks.run
 """
@@ -164,45 +162,6 @@ def write_summary(results: dict) -> dict:
     return summary
 
 
-def build_bench9(results: dict) -> dict:
-    """Perf-trajectory artifact for the streaming-engine work (BENCH_9):
-    the throughput numbers a future regression hunt needs in one place —
-    batched vs scalar configs/sec, chunked-vs-monolithic ratios, and the
-    pipeline overlap figures, each tagged with the grid it ran on."""
-    sweep_res = results.get("sweep") or {}
-    pareto_res = results.get("pareto") or {}
-    pipe = pareto_res.get("pipeline") or {}
-    return {
-        "bench": "device_resident_streaming_pipeline",
-        "smoke": bool(pareto_res.get("smoke", sweep_res.get("smoke", True))),
-        "batched_configs_per_s": sweep_res.get("batched_configs_per_s"),
-        "scalar_configs_per_s": sweep_res.get("scalar_configs_per_s"),
-        "batched_over_scalar": sweep_res.get("speedup"),
-        "pipelined_configs_per_s": sweep_res.get("pipelined_configs_per_s"),
-        "chunked_over_monolithic": {
-            s: (pareto_res.get(s) or {}).get("chunked_over_monolithic")
-            for s in ("network", "codesign")},
-        "pipeline": pipe,
-        "pipelined_over_host_serial": pipe.get("pipelined_over_host_serial"),
-        "overlap_gain_over_device_serial":
-            pipe.get("overlap_gain_over_device_serial"),
-        "grid_sizes": {
-            "sweep": sweep_res.get("n_configs"),
-            "network": (pareto_res.get("network") or {}).get("n_configs"),
-            "pipeline": pipe.get("n_configs"),
-            "codesign_joint":
-                (pareto_res.get("codesign") or {}).get("n_joint_points"),
-        },
-    }
-
-
-def write_bench9(results: dict) -> dict:
-    bench = build_bench9(results)
-    ARTIFACTS.mkdir(exist_ok=True)
-    (ARTIFACTS / "BENCH_9.json").write_text(json.dumps(bench, indent=2))
-    return bench
-
-
 def main() -> None:
     use_compile_cache()
     # set here, not at import: the smoke tests import this module in-process
@@ -245,12 +204,6 @@ def main() -> None:
           f"{'PASS' if lint_res['ok'] else 'FAIL'}")
 
     summary = write_summary(results)
-    bench9 = write_bench9(results)
-    print("# perf trajectory -> artifacts/BENCH_9.json")
-    if bench9["pipelined_over_host_serial"] is not None:
-        print(f"bench9/pipelined_over_host_serial,0,"
-              f"{bench9['pipelined_over_host_serial']:.2f}x on "
-              f"{bench9['grid_sizes']['pipeline']} rows")
     print("# consolidated summary -> artifacts/summary.json")
     for k, p in summary["perf"].items():
         print(f"summary/perf/{k},0,{p['value']:.2f} vs bar {p['bar']} "

@@ -97,6 +97,7 @@ from repro.core.sweep import (
     SweepResult,
     _as_f64,
     _decode_program,
+    _fetch_bytes,
     _nets_program,
     _network_columns_arrays,
     _run_pipeline,
@@ -226,10 +227,13 @@ def pareto_mask(points) -> np.ndarray:
         pts = np.concatenate(
             [pts, np.full((npad - n, pts.shape[1]), np.inf)], axis=0)
     ranks = np.empty(pts.shape, np.float32)
-    for j in range(pts.shape[1]):
-        _, inv = np.unique(pts[:, j], return_inverse=True)
-        ranks[:, j] = inv
-    return np.asarray(_pareto_mask_jit(jnp.asarray(ranks)))[:n]
+    with jax.profiler.TraceAnnotation("repro.front.rank", points=n):
+        for j in range(pts.shape[1]):
+            _, inv = np.unique(pts[:, j], return_inverse=True)
+            ranks[:, j] = inv
+    with jax.profiler.TraceAnnotation("repro.front.mask", points=n,
+                                      padded=npad):
+        return np.asarray(_pareto_mask_jit(jnp.asarray(ranks)))[:n]
 
 
 def pareto_mask_reference(points, block: int = 2048) -> np.ndarray:
@@ -296,11 +300,14 @@ def _dominated_by(pts: np.ndarray, front_pts: np.ndarray) -> np.ndarray:
         return np.zeros(n, bool)
     out = np.zeros(n, bool)
     block = max(256, 8_000_000 // max(1, front_pts.shape[0]))
-    for s in range(0, n, block):
-        p = pts[s:s + block]
-        le = (front_pts[None, :, :] <= p[:, None, :]).all(-1)
-        ne = (front_pts[None, :, :] != p[:, None, :]).any(-1)
-        out[s:s + block] = (le & ne).any(1)
+    with jax.profiler.TraceAnnotation("repro.merge.prefilter", rows=n,
+                                      front=int(front_pts.shape[0])) as span:
+        for s in range(0, n, block):
+            p = pts[s:s + block]
+            le = (front_pts[None, :, :] <= p[:, None, :]).all(-1)
+            ne = (front_pts[None, :, :] != p[:, None, :]).any(-1)
+            out[s:s + block] = (le & ne).any(1)
+        span.set_metadata(kept=n - int(np.count_nonzero(out)))
     return out
 
 
@@ -374,11 +381,16 @@ def _merge_into(front: Optional[ParetoFront], pts: np.ndarray,
     """Merge a raw point block into a running front: prefilter points the
     front already dominates, then extract over front + survivors."""
     idx = np.asarray(idx).astype(np.int64)
-    if front is not None and front.size:
-        keep = ~_dominated_by(pts, front.points)
-        pts = np.concatenate([front.points, pts[keep]], axis=0)
-        idx = np.concatenate([front.indices, idx[keep]], axis=0)
-    return _front_of(pts, idx, objectives)
+    front_in = front.size if front is not None else 0
+    with jax.profiler.TraceAnnotation("repro.merge", rows=int(pts.shape[0]),
+                                      front_in=front_in) as span:
+        if front_in:
+            keep = ~_dominated_by(pts, front.points)
+            pts = np.concatenate([front.points, pts[keep]], axis=0)
+            idx = np.concatenate([front.indices, idx[keep]], axis=0)
+        front = _front_of(pts, idx, objectives)
+        span.set_metadata(front_out=front.size)
+    return front
 
 
 def pareto_front(result: SweepResult,
@@ -562,15 +574,25 @@ def codesign_pareto(
     def fold(result):
         nonlocal front
         start, stop, out = result
-        jax.block_until_ready(out)
         valid = stop - start
-        pts = np.stack(
-            [np.asarray(out[k], np.float64)[:, :valid] for k in objectives],
-            axis=-1).reshape(n_mix * valid, len(objectives))
-        idx = (mix_off + np.arange(start, stop)[None, :]).reshape(-1)
-        front = _merge_into(front, pts, idx, objectives)
+        with jax.profiler.TraceAnnotation("repro.chunk.fold", start=int(start),
+                                          rows=n_mix * valid):
+            with jax.profiler.TraceAnnotation("repro.chunk.wait"):
+                jax.block_until_ready(out)
+            with jax.profiler.TraceAnnotation(
+                    "repro.chunk.fetch",
+                    bytes=_fetch_bytes(*(out[k] for k in objectives))):
+                pts = np.stack(
+                    [np.asarray(out[k], np.float64)[:, :valid]
+                     for k in objectives],
+                    axis=-1).reshape(n_mix * valid, len(objectives))
+            idx = (mix_off + np.arange(start, stop)[None, :]).reshape(-1)
+            front = _merge_into(front, pts, idx, objectives)
 
-    _run_pipeline(range(0, n, step), make_task, fold, depth)
+    starts = range(0, n, step)
+    with jax.profiler.TraceAnnotation("repro.search", designs=n_mix * n,
+                                      chunks=len(starts)):
+        _run_pipeline(starts, make_task, fold, depth)
     assert front is not None  # n > 0 and n_mix > 0 guarantee >= 1 chunk
     return front, spec
 

@@ -730,6 +730,20 @@ def _config_sharding():
         mesh, jax.sharding.PartitionSpec("configs"))
 
 
+def _dispatch(task, start: int):
+    """Run one chunk task: decode and chunk programs launched, not waited
+    for (span `repro.chunk.dispatch`)."""
+    with jax.profiler.TraceAnnotation("repro.chunk.dispatch",
+                                      start=int(start)):
+        return task()
+
+
+def _fetch_bytes(*arrays) -> int:
+    """Bytes a host copy of `arrays` moves off the device (host arrays
+    move nothing)."""
+    return sum(int(a.nbytes) for a in arrays if isinstance(a, jax.Array))
+
+
 def _run_pipeline(starts, make_task, fold, depth: int) -> None:
     """Double-buffered chunk pipeline: at most `depth` chunk tasks in flight
     beyond the one being folded, folds strictly in submission order (so any
@@ -741,12 +755,12 @@ def _run_pipeline(starts, make_task, fold, depth: int) -> None:
     starts = list(starts)
     if depth <= 0 or len(starts) <= 1:
         for start in starts:
-            fold(make_task(start)())
+            fold(_dispatch(make_task(start), start))
         return
     pending = deque()
     with ThreadPoolExecutor(max_workers=1) as ex:
         for start in starts:
-            pending.append(ex.submit(make_task(start)))
+            pending.append(ex.submit(_dispatch, make_task(start), start))
             while len(pending) > depth:
                 fold(pending.popleft().result())
         while pending:
@@ -899,18 +913,28 @@ def sweep_chunked(
     def fold(result):
         nonlocal carry
         start, stop, topo_id, nets, mets = result
-        jax.block_until_ready(mets)
         valid = stop - start
-        out = {k: np.asarray(v, np.float64) for k, v in mets.items()}
-        out = {k: v[..., :valid] for k, v in broadcast_metrics(out, np).items()}
-        nets = {k: np.asarray(v)[..., :valid] for k, v in nets.items()}
-        topo_id = np.asarray(topo_id)[:valid]
-        carry = reducer.step(carry, SweepChunk(
-            spec=spec, start=start, stop=stop, topo_id=topo_id,
-            nets=nets, metrics=out))
+        with jax.profiler.TraceAnnotation("repro.chunk.fold", start=int(start),
+                                          rows=int(valid)):
+            with jax.profiler.TraceAnnotation("repro.chunk.wait"):
+                jax.block_until_ready(mets)
+            with jax.profiler.TraceAnnotation(
+                    "repro.chunk.fetch", bytes=_fetch_bytes(
+                        *mets.values(), *nets.values(), topo_id)):
+                out = {k: np.asarray(v, np.float64) for k, v in mets.items()}
+                out = {k: v[..., :valid]
+                       for k, v in broadcast_metrics(out, np).items()}
+                nets = {k: np.asarray(v)[..., :valid] for k, v in nets.items()}
+                topo_id = np.asarray(topo_id)[:valid]
+            carry = reducer.step(carry, SweepChunk(
+                spec=spec, start=start, stop=stop, topo_id=topo_id,
+                nets=nets, metrics=out))
 
-    _run_pipeline(range(0, n, chunk_size), make_task, fold, depth)
-    return reducer.finish(carry, spec)
+    starts = range(0, n, chunk_size)
+    with jax.profiler.TraceAnnotation("repro.search", designs=int(n),
+                                      chunks=len(starts)):
+        _run_pipeline(starts, make_task, fold, depth)
+        return reducer.finish(carry, spec)
 
 
 def sweep_scalar_reference(
