@@ -292,22 +292,91 @@ def _front_exact(points: np.ndarray, indices: np.ndarray,
                        np.asarray(indices)[mask].astype(np.int64)).canonical()
 
 
+_STAIRCASE_CELLS = 1 << 21  # float64 cells of the staircases built at once
+
+
+def _row_keys(x: np.ndarray) -> np.ndarray:
+    """One opaque, sortable key per row of a float64 array: rows equal under
+    float == in every column (-0.0 and 0.0 too) share a key, and a row that
+    holds a NaN shares none with a row that holds no NaN."""
+    x = np.ascontiguousarray(x + 0.0)  # -0.0 + 0.0 is 0.0
+    return x.view(np.dtype((np.void, x.itemsize * x.shape[1])))[:, 0]
+
+
 def _dominated_by(pts: np.ndarray, front_pts: np.ndarray) -> np.ndarray:
-    """Which of `pts` are weakly dominated by some member of `front_pts`
-    (numpy, blockwise) — the cheap prefilter before exact merge."""
+    """Which of `pts` are weakly dominated by some member of `front_pts`:
+    some f <= p in every objective and f != p in one (m in {2, 3}) — the
+    prefilter before exact merge.
+
+    An exact offline query, O((rows + front) log front + front^2), with no
+    precondition on `front_pts` (it need not be a front):
+      * `le`: some f <= p.  Front points sorted by objective 0 make the
+        candidates of row p a prefix (f0 <= p0); for every prefix length
+        that occurs, the running minimum of objective 2 along the prefix's
+        points in objective-1 order is a staircase, and one searchsorted on
+        objective 1 reads whether a prefix point also has f2 <= p2.
+      * strictness: every f <= p differs from p unless p equals a front
+        point f*; such a p is dominated iff f* is dominated within
+        `front_pts` (O(front^2), the brute force on at most a few hundred
+        points), and only rows tied with their prefix's last f0 can be one.
+    A NaN compares False and sorts last, so a front point holding one
+    enters no prefix, staircase or match; a row holding one is never
+    dominated.  2 objectives take a zero third.
+    """
     n = pts.shape[0]
     if front_pts.size == 0 or n == 0:
         return np.zeros(n, bool)
-    out = np.zeros(n, bool)
-    block = max(256, 8_000_000 // max(1, front_pts.shape[0]))
+    m = pts.shape[1]
+    if m not in (2, 3) or front_pts.shape[1] != m:
+        raise ValueError(f"expected (n, 2|3) points and front, got "
+                         f"{pts.shape} and {front_pts.shape}")
     with jax.profiler.TraceAnnotation("repro.merge.prefilter", rows=n,
                                       front=int(front_pts.shape[0])) as span:
-        for s in range(0, n, block):
-            p = pts[s:s + block]
-            le = (front_pts[None, :, :] <= p[:, None, :]).all(-1)
-            ne = (front_pts[None, :, :] != p[:, None, :]).any(-1)
-            out[s:s + block] = (le & ne).any(1)
-        span.set_metadata(kept=n - int(np.count_nonzero(out)))
+        p = np.asarray(pts, np.float64)
+        f = np.asarray(front_pts, np.float64)
+        if m == 2:
+            p = np.concatenate([p, np.zeros((n, 1))], axis=1)
+            f = np.concatenate([f, np.zeros((f.shape[0], 1))], axis=1)
+        k = f.shape[0]
+        by0 = np.argsort(f[:, 0])
+        f0 = f[by0, 0]
+        pre = np.searchsorted(f0, p[:, 0], side="right")
+        pre[np.isnan(p).any(axis=1)] = 0
+        # the staircase of prefix length l: column c holds the min f2 over
+        # the first c points in objective-1 order whose f0-rank is below l
+        # (NaN where there is none, which compares False below)
+        by1 = np.argsort(f[:, 1])
+        rank0 = np.empty(k, np.intp)
+        rank0[by0] = np.arange(k)
+        rank0 = np.concatenate([[k], rank0[by1]])  # column 0 holds no point
+        f2 = np.concatenate([[np.nan], f[by1, 2]])
+        col = np.searchsorted(f[by1, 1], p[:, 1], side="right")
+        lens = np.flatnonzero(np.bincount(pre, minlength=k + 1)[1:]) + 1
+        slot = np.zeros(k + 1, np.intp)
+        slot[lens] = np.arange(lens.size)
+        best = np.full(n, np.nan)
+        per = max(1, _STAIRCASE_CELLS // (k + 1))
+        for s in range(0, lens.size, per):
+            ls = lens[s:s + per]
+            stairs = np.fmin.accumulate(
+                np.where(rank0 < ls[:, None], f2, np.nan), axis=1)
+            rows = np.flatnonzero((pre >= ls[0]) & (pre <= ls[-1]))
+            best[rows] = stairs[slot[pre[rows]] - s, col[rows]]
+        out = best <= p[:, 2]
+        # a row equal to a front point is dominated iff that point is
+        # dominated within the front
+        cand = np.flatnonzero(out)
+        cand = cand[f0[pre[cand] - 1] == p[cand, 0]]
+        keys = _row_keys(f)
+        order = np.argsort(keys)
+        keys = keys[order]
+        at = np.searchsorted(keys, _row_keys(p[cand])).clip(max=k - 1)
+        hit = keys[at] == _row_keys(p[cand])
+        equal = cand[hit]
+        if equal.size:
+            out[equal] = ~pareto_mask_reference(f)[order[at[hit]]]
+        span.set_metadata(kept=n - int(np.count_nonzero(out)),
+                          prefixes=int(lens.size), equal=int(equal.size))
     return out
 
 
@@ -319,7 +388,7 @@ def _front_of(points: np.ndarray, indices: np.ndarray,
               block: int = _FRONT_BLOCK) -> ParetoFront:
     """Exact front of an arbitrary point cloud.  Large clouds are folded
     block-by-block: each block is prefiltered against the running front
-    (cheap vectorized numpy dominance, O(block * front_size)), and only the
+    (`_dominated_by`, O((block + front_size) log front_size)), and only the
     survivors go through the exact jitted sort+scan — so the sequential scan
     never sees more than front_size + block points at once.  A dominated
     point is always dominated by some *front* member (dominance is

@@ -41,9 +41,11 @@ from repro.core.sweep import (
     sweep_chunked,
 )
 from repro.core.search import (
+    _FRONT_BLOCK,
     OBJECTIVES,
     ParetoFront,
     _coordinate_int_search,
+    _dominated_by,
     _trust_region_descent,
     codesign_pareto,
     merge_fronts,
@@ -129,6 +131,89 @@ def test_merge_fronts_associativity():
     merged = merge_fronts(*part_fronts)
     assert np.array_equal(whole.points, merged.points)
     assert np.array_equal(whole.indices, merged.indices)
+
+
+# ---------------------------------------------------------------------------
+# the merge's dominance prefilter == the blockwise broadcast it replaced
+# ---------------------------------------------------------------------------
+
+
+def _dominated_by_blockwise(pts, front_pts):
+    """The prefilter's former body: every row against every front point,
+    f <= p in all objectives and f != p in one, in row blocks."""
+    n = pts.shape[0]
+    if front_pts.size == 0 or n == 0:
+        return np.zeros(n, bool)
+    out = np.zeros(n, bool)
+    block = max(256, 8_000_000 // max(1, front_pts.shape[0]))
+    for s in range(0, n, block):
+        p = pts[s:s + block]
+        le = (front_pts[None, :, :] <= p[:, None, :]).all(-1)
+        ne = (front_pts[None, :, :] != p[:, None, :]).any(-1)
+        out[s:s + block] = (le & ne).any(1)
+    return out
+
+
+def _prefilter_case(case, m):
+    """(rows, front) of one named case; the front need not be a front."""
+    rng = np.random.default_rng([sum(map(ord, case)), m])
+    if case == "float":
+        return rng.normal(size=(3000, m)), rng.normal(size=(40, m))
+    if case == "integer_ties":
+        return (rng.integers(0, 5, (3000, m)).astype(float),
+                rng.integers(0, 5, (60, m)).astype(float))
+    if case == "duplicate_front":
+        front = rng.integers(0, 6, (30, m)).astype(float)
+        front = front[pareto_mask_reference(front)]
+        return (rng.integers(0, 6, (2000, m)).astype(float),
+                np.concatenate([front, front[::2], front[:1]]))
+    if case == "rows_equal_front":
+        front = rng.normal(size=(200, m))
+        front = front[pareto_mask_reference(front)]
+        rows = np.concatenate([front, front[::3], rng.normal(size=(500, m))])
+        return rng.permutation(rows), front
+    if case == "front_not_a_front":
+        # dominated and duplicated front points, rows equal to each kind
+        front = rng.integers(0, 4, (80, m)).astype(float)
+        rows = np.concatenate([front, rng.integers(0, 4, (1000, m))])
+        return rng.permutation(rows), front
+    if case == "nan_inf_signed_zero":
+        vals = np.array([np.nan, np.inf, -np.inf, 0.0, -0.0, 1.0, -1.0])
+        return rng.choice(vals, (4000, m)), rng.choice(vals, (50, m))
+    if case == "signed_zero_copies":
+        # rows equal to front points but for the sign of their zeros
+        front = rng.integers(-2, 3, (60, m)).astype(float)
+        front = front[front.sum(axis=1) == 0]  # an antichain holding zeros
+        rows = np.concatenate([np.where(front == 0, 0.0, front), -front])
+        return rows, np.where(front == 0, -0.0, front)
+    if case == "empty_rows":
+        return np.zeros((0, m)), rng.normal(size=(10, m))
+    if case == "empty_front":
+        return rng.normal(size=(100, m)), np.zeros((0, m))
+    if case == "chunk_against_front":
+        # a chunk of the engine's size against a front of about 400 points
+        front = rng.dirichlet(np.ones(m), 400)
+        rows = rng.dirichlet(np.ones(m), 65536) * 1.02 + rng.normal(
+            scale=0.01, size=(65536, m))
+        rows[:4000, 0] = front[rng.integers(0, 400, 4000), 0]
+        rows[4000:5000] = front[rng.integers(0, 400, 1000)]
+        return rows, front
+    raise KeyError(case)
+
+
+@pytest.mark.parametrize("case,m", [
+    ("float", 2), ("float", 3), ("integer_ties", 2), ("integer_ties", 3),
+    ("duplicate_front", 3), ("rows_equal_front", 2),
+    ("rows_equal_front", 3), ("front_not_a_front", 2),
+    ("front_not_a_front", 3), ("nan_inf_signed_zero", 2),
+    ("nan_inf_signed_zero", 3), ("signed_zero_copies", 2),
+    ("signed_zero_copies", 3), ("empty_rows", 3), ("empty_front", 3),
+    ("chunk_against_front", 3)])
+def test_dominated_by_matches_blockwise_broadcast(case, m):
+    rows, front = _prefilter_case(case, m)
+    got = _dominated_by(rows, front)
+    assert got.dtype == bool and got.shape == (rows.shape[0],)
+    assert np.array_equal(got, _dominated_by_blockwise(rows, front))
 
 
 # ---------------------------------------------------------------------------
@@ -282,6 +367,53 @@ def test_codesign_front_matches_bruteforce():
         np.where(pareto_mask_reference(pts))[0].tolist())
     # padded-mix kernel: the 1-chiplet mix must behave as if unpadded
     assert out["latency_s"].shape == (3, spec.n)
+
+
+STREAM_AXES = dict(n_gateways=tuple(range(8, 65, 4)),
+                   n_lambda=(2, 4, 8, 12, 16, 24),
+                   mem_bw_bytes_per_s=(25e9, 50e9, 100e9, 200e9))
+
+
+def _same_as_bruteforce(front, pts):
+    """`front` holds exactly the brute-force front of `pts` (flat indices)."""
+    mask = pareto_mask_reference(pts)
+    ref = ParetoFront(OBJECTIVES, pts[mask], np.flatnonzero(mask)).canonical()
+    got = front.canonical()
+    assert np.array_equal(got.points, ref.points)
+    assert np.array_equal(got.indices, ref.indices)
+
+
+def test_streaming_fronts_fold_blocks_through_prefilter():
+    """Chunks of more than `_FRONT_BLOCK` rows, so the first merge folds
+    several blocks through the prefilter: both searches still return the
+    brute-force front of the whole grid."""
+    axes = dict(STREAM_AXES, n_mem_chiplets=(1, 2, 4, 8))
+    chunk = 4608
+    assert _FRONT_BLOCK < chunk < grid_spec(**axes).n
+    res = sweep(TRAFFIC, **axes)
+    _same_as_bruteforce(pareto_search(TRAFFIC, chunk_size=chunk, **axes),
+                        np.stack([res.metrics[k] for k in OBJECTIVES], -1))
+
+    wl = CNN_WORKLOADS["LeNet5"]()
+    mixes = [[ChipletSpec(512, 32)], [ChipletSpec(256, 16)],
+             [ChipletSpec(512, 9), ChipletSpec(512, 49)],
+             [ChipletSpec(256, 16), ChipletSpec(128, 128)]]
+    axes = dict(STREAM_AXES, n_mem_chiplets=(1, 2))
+    topologies = ("trine", "tree", "elec")
+    chunk = 1100
+    spec = grid_spec(topologies, **axes)
+    assert _FRONT_BLOCK < len(mixes) * chunk and chunk < spec.n
+    front, _ = codesign_pareto(wl, mixes, topologies=topologies,
+                               chunk_size=chunk, **axes)
+    from repro.core.accelerator import evaluate_accelerator_grid
+    from repro.core.sweep import _network_columns_arrays
+    cols, topo_id = spec.chunk_cols(0, spec.n)
+    nets = _network_columns_arrays(cols, topo_id, spec.topologies)
+    out = evaluate_accelerator_grid(
+        wl, mixes, nets, cols,
+        cols["n_mem_chiplets"] * cols["mem_bw_bytes_per_s"])
+    _same_as_bruteforce(
+        front, np.stack([out[k] for k in OBJECTIVES], -1).reshape(-1, 3))
 
 
 def test_accelerator_grid_device_corner_sweep_scalar_nets():

@@ -83,6 +83,10 @@ def _check_pipeline(spans, designs, rows, starts, fetch_bytes):
     prefilters = spans["repro.merge.prefilter"]
     assert prefilters and all(
         0 <= p.args["kept"] <= p.args["rows"] for p in prefilters)
+    # how the sort-based query engaged: staircases built, exact matches
+    assert all(0 <= p.args["equal"] <= p.args["rows"] - p.args["kept"]
+               and 1 <= p.args["prefixes"] <= p.args["front"]
+               for p in prefilters)
     assert all(any(m.holds(p) for m in merges) for p in prefilters)
     masks = spans["repro.front.mask"]
     assert len(masks) == len(spans["repro.front.rank"]) >= len(merges)
