@@ -25,6 +25,7 @@ from __future__ import annotations
 import numpy as np
 
 from benchlib.harness import Check, reference_module
+from benchlib.traces import Event, TraceData
 
 # Limits: set from the readings in PERF.md (sound runs on the chip against
 # the float32 control); see the `checks` key of each traffic file.
@@ -197,3 +198,20 @@ class CodesignSearch(_Search):
         return ref.score_joint_rows(self.grid, self.config["workload"]["layers"],
                                     self.mixes[mix], self.config["accelerator"],
                                     r0, r0 + (e - s), xp, dtype)
+
+
+MS = 1e6  # ns
+
+
+def made_unit():
+    """A made trace of one search, for the readers' tests: (trace,
+    counters).  Two chunks of decode + body + single, one front scan, in a
+    20 ms unit."""
+    mods = [Event("jit_decode(1)", 1 * MS, 2 * MS),
+            Event("jit_body(2)", 2 * MS, 4 * MS),
+            Event("jit_single(3)", 4 * MS, 5 * MS),
+            Event("jit_decode(1)", 6 * MS, 7 * MS),
+            Event("jit_body(2)", 7 * MS, 9 * MS),
+            Event("jit__pareto_mask_core(4)", 12 * MS, 18 * MS)]
+    return (TraceData({0: mods}, {}, [Event("bench.unit", 0, 20 * MS)]),
+            {"chunks_per_search": 2})

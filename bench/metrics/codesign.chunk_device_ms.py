@@ -3,4 +3,6 @@
 
 from benchlib.harness import BENCH, load_file_module
 
-read = load_file_module(BENCH / "metrics" / "engine.chunk_device_ms.py").read
+_ENGINE = load_file_module(BENCH / "metrics" / "engine.chunk_device_ms.py")
+read = _ENGINE.read
+MADE_READING = _ENGINE.MADE_READING

@@ -7,6 +7,9 @@ builder) and, in co-design, the accelerator grid kernel (the vmapped
 program executions in the trace; chunks = searches x chunks per search.
 """
 
+# the reading of `engine.made_unit()`
+MADE_READING = 3.5
+
 PATTERN = r"^jit_(decode|body|single)$"
 
 

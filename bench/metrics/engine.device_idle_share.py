@@ -3,6 +3,9 @@
 1 - (union of device busy intervals) / (traced window), from the trace.
 """
 
+# the reading of `engine.made_unit()`
+MADE_READING = 35.0
+
 
 def read(win, cell):
     return 100.0 * win.idle_share

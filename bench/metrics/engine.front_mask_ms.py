@@ -5,6 +5,9 @@
 the chunk's survivors.  Read from the program executions in the trace.
 """
 
+# the reading of `engine.made_unit()`
+MADE_READING = 6.0
+
 PATTERN = r"^jit__pareto_mask_core$"
 
 

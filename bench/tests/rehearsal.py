@@ -1,33 +1,34 @@
-"""Small sizes at which the tests drive whole cells on the CPU."""
+"""Small sizes at which the tests drive whole cells on the CPU: each cell's
+traffic file gives its own under the key `small` (overrides of the
+configuration's and the traffic's keys)."""
 
 import io
 import json
 import sys
 import time
 from pathlib import Path
+from unittest import mock
 
 BENCH = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(BENCH), str(BENCH.parent / "src")]
 
 from benchlib import harness  # noqa: E402
 
-SMALL = {
-    "interposer.net_front": {
-        "config": {"network_axes": {"n_gateways": [8, 16, 32],
-                                    "n_lambda": [4, 8],
-                                    "mem_bw_bytes_per_s": [5e10, 1e11]},
-                   "memory_axis": {"n_mem_chiplets": [2, 4]}},
-        "traffic": {"chunk_size": 16, "reference_block": 16}},
-    "interposer.codesign_front": {
-        "config": {"network_axes": {"n_gateways": [8, 16, 32],
-                                    "n_lambda": [4, 8],
-                                    "mem_bw_bytes_per_s": [5e10, 1e11]}},
-        "traffic": {"chunk_size": 12, "reference_block": 12}},
-}
-
 
 def benchmark() -> dict:
     return harness.load_json(BENCH.parent / "BENCHMARK.json")
+
+
+def _small() -> dict:
+    """{workload: its traffic file's `small` overrides}, for every cell."""
+    out = {}
+    for w in benchmark()["workloads"]:
+        traffic = harness.load_json(BENCH / "traffic" / f"{w['traffic']}.json")
+        out[w["name"]] = traffic["small"]
+    return out
+
+
+SMALL = _small()
 
 
 def overrides(workload: str) -> dict:
@@ -35,12 +36,19 @@ def overrides(workload: str) -> dict:
 
 
 def run(workload: str, seed: int = 2**31 + 17, seconds: float = 0.5):
-    """Drive the whole cell at a small size; returns (result, stderr)."""
+    """Drive the whole cell at a small size; returns (result, stderr).  The
+    CPU gets a nominal peak, so that a training cell reports its
+    utilization; no CPU number is ever reported as a chip's."""
+    import jax
+    from benchlib import roofline
     out, err = io.StringIO(), io.StringIO()
-    result = harness.run_cell(workload, seed, seconds, False,
-                              time.perf_counter(), bench=benchmark(),
-                              overrides=overrides(workload),
-                              require_tpu=False, out=out, err=err)
+    nominal = {jax.devices()[0].device_kind: {"bf16_flops": 1e12,
+                                              "hbm_bytes_per_s": 1e11}}
+    with mock.patch.dict(roofline.PEAKS, nominal):
+        result = harness.run_cell(workload, seed, seconds, False,
+                                  time.perf_counter(), bench=benchmark(),
+                                  overrides=overrides(workload),
+                                  require_tpu=False, out=out, err=err)
     assert json.loads(out.getvalue().strip().splitlines()[-1]) == \
         json.loads(json.dumps(result))
     return result, err.getvalue()

@@ -7,6 +7,7 @@ spans, each a photonic_mac kernel call, a 20 ms host sleep inside a
 `bench.host_wait` span, and an ssm_scan kernel call."""
 
 import glob
+import importlib
 
 import pytest
 
@@ -94,28 +95,19 @@ def test_chip_trace_units_kernels_and_idle():
     assert b["idle_gaps"][0][1] == pytest.approx(0.02, rel=0.25)
 
 
-def _engine_search():
-    """One search: two chunks of decode + body + single, one front scan."""
-    mods = [Event("jit_decode(1)", 1 * MS, 2 * MS),
-            Event("jit_body(2)", 2 * MS, 4 * MS),
-            Event("jit_single(3)", 4 * MS, 5 * MS),
-            Event("jit_decode(1)", 6 * MS, 7 * MS),
-            Event("jit_body(2)", 7 * MS, 9 * MS),
-            Event("jit__pareto_mask_core(4)", 12 * MS, 18 * MS)]
-    return TraceData({0: mods}, {}, [Event("bench.unit", 0, 20 * MS)])
-
-
-ENGINE_READINGS = {"front_mask_ms": 6.0, "chunk_device_ms": 3.5,
-                   "device_idle_share": 35.0}
-
-
 @pytest.mark.parametrize("entry", benchmark()["per_layer"],
                          ids=lambda m: m["name"])
 def test_engine_readers_on_a_made_search(entry):
-    """Each cell's readers give the same readings of one search."""
-    cell = harness.Cell(name=entry["workloads"][0], workload={}, config={},
-                        traffic={}, seed=0, seconds=0.0, trace=True, chips=1,
-                        counters={"chunks_per_search": 2})
+    """Each reader, on the made unit trace of the cell class that drives its
+    first cell (`made_unit()` of that class's module: an engine search, a
+    training step), reads what its file states (`MADE_READING`)."""
+    wl, _, traffic = harness.cell_spec(benchmark(), entry["workloads"][0])
+    module = importlib.import_module(
+        "benchlib." + traffic["driver"].split(":")[0])
+    trace, counters = module.made_unit()
+    cell = harness.Cell(name=wl["name"], workload=wl, config={},
+                        traffic=traffic, seed=0, seconds=0.0, trace=True,
+                        chips=1, counters=dict(counters))
     reader = harness.load_file_module(BENCH / "metrics" / f"{entry['name']}.py")
-    value = reader.read(traces.Window(_engine_search()), cell)
-    assert value == pytest.approx(ENGINE_READINGS[entry["name"].split(".", 1)[1]])
+    value = reader.read(traces.Window(trace), cell)
+    assert value == pytest.approx(reader.MADE_READING, rel=1e-12)
