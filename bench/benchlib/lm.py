@@ -8,18 +8,22 @@ kernels and the photonic-MAC numerics on, as `launch/train.py
 batch, `Trainer._step` (the jitted, donating train step), and a wait for its
 loss.  No checkpoint is saved.
 
-Set-up makes the weights from the seed with the reference's `init_params`
-(one jitted call on the device), puts them in the trainer's state, and
-drives the first `checked_steps` steps through the window's own `step()`;
-the first compiles.  It keeps the losses of those steps, their batches,
-the first AdamW moment after step 1 (the first gradient as the optimizer
-took it: m / (1 - b1)) and the weights after the last, on the host.  The
-window then goes on from that same trainer.
+This module names no architecture: the configuration's reference module
+(configs/*.reference.py; its contract is in bench/README.md) owns the
+weights' layout and the model's FLOPs.  Set-up makes the weights from the
+seed with its `init_params` (one jitted call on the device), puts them in
+the trainer's state through its `to_program`, and drives the first
+`checked_steps` steps through the window's own `step()`; the first
+compiles.  It keeps the losses of those steps, their batches, the first
+AdamW moment after step 1 (the first gradient as the optimizer took it:
+m / (1 - b1)) and the weights after the last, on the host.  The window
+then goes on from that same trainer.
 
 After the window the plain reference (configs/*.reference.py) replays those
 steps from the same seed on batches that the benchmark makes itself, and
-the check compares, leaf by leaf (each layer's slice of a stacked weight a
-leaf of its own; the norms taken on the device):
+the check compares, leaf by leaf (the reference's `leaves`: each layer's
+slice of a stacked weight a leaf of its own; the norms taken on the
+device):
 
   loss_rel_err     the largest relative gap of a checked step's loss;
   grad_norm_gap    the worst leaf's gap between the norms of the program's
@@ -94,40 +98,6 @@ def batch_at(traffic: dict, vocab: int, seed: int, step: int):
     return toks[:, :-1], toks[:, 1:]
 
 
-def to_program(ref: dict) -> dict:
-    """The reference's weights in the program's tree (no copies)."""
-    return {"embed": ref["embed"], "lm_head": ref["lm_head"],
-            "final_norm": ref["final_norm"],
-            "stages": [{"attn_0": {
-                "attn": {"wq": ref["wq"], "wk": ref["wk"], "wv": ref["wv"],
-                         "wo": ref["wo"], "norm": ref["attn_norm"]},
-                "mlp": {"wi": ref["wi"], "wg": ref["wg"], "wo": ref["wd"],
-                        "norm": ref["mlp_norm"]}}}]}
-
-
-def from_program(p: dict) -> dict:
-    a = p["stages"][0]["attn_0"]["attn"]
-    m = p["stages"][0]["attn_0"]["mlp"]
-    return {"embed": p["embed"], "lm_head": p["lm_head"],
-            "final_norm": p["final_norm"], "wq": a["wq"], "wk": a["wk"],
-            "wv": a["wv"], "wo": a["wo"], "attn_norm": a["norm"],
-            "wi": m["wi"], "wg": m["wg"], "wd": m["wo"],
-            "mlp_norm": m["norm"]}
-
-
-def leaves(ref_layout: dict, layers: int) -> dict:
-    """{leaf name: array} (host or device), each layer's slice of a stacked
-    weight a leaf of its own."""
-    out = {}
-    for name, x in sorted(ref_layout.items()):
-        if name in ("embed", "lm_head", "final_norm"):
-            out[name] = x
-        else:
-            for i in range(layers):
-                out[f"{name}.{i}"] = x[i]
-    return out
-
-
 def bf16_exact_share(arrays) -> float:
     n = exact = 0
     for x in arrays:
@@ -184,10 +154,10 @@ class Training:
         self.config, self.traffic = cell.config, cell.traffic
         self.opt = dict(self.traffic["optimizer"])
         self.checked = int(self.traffic["checked_steps"])
-        self.layers = int(self.config["num_hidden_layers"])
         self.vocab = int(self.config["vocab_size"])
         self.tokens = int(self.traffic["batch"]) * int(self.traffic["seq_len"])
-        self.flops_per_token = roofline.lm_train_flops_per_token(
+        self.ref = reference_module(self.config)
+        self.flops_per_token = self.ref.model_flops_per_token(
             self.config, int(self.traffic["seq_len"]))
         # the program's settings that a control or a test changes
         self.program_overrides: dict = {}
@@ -208,8 +178,8 @@ class Training:
         self.trainer = Trainer(cfg, opt, data,
                                TrainerConfig(ckpt_dir=tempfile.gettempdir()),
                                resume=False)
-        ref = reference_module(self.config)
-        params = to_program(ref.init_params(self.config, self.cell.seed))
+        ref, config = self.ref, self.config
+        params = ref.to_program(ref.init_params(config, self.cell.seed), config)
         want = jax.tree.map(lambda x: (x.shape, x.dtype),
                             self.trainer.state.params)
         got = jax.tree.map(lambda x: (x.shape, x.dtype), params)
@@ -225,15 +195,16 @@ class Training:
         for k in range(self.checked):
             self.step()
             if k == 0:
-                m = from_program(jax.device_get(self.trainer.state.m))
-                self.moment_exact = bf16_exact_share(m.values())
-                self.first_grad = leaves(jax.tree.map(
+                m = ref.from_program(jax.device_get(self.trainer.state.m),
+                                     config)
+                self.moment_exact = bf16_exact_share(jax.tree.leaves(m))
+                self.first_grad = ref.leaves(jax.tree.map(
                     lambda m: np.asarray(m, np.float32) / (1 - opt.b1), m),
-                    self.layers)
+                    config)
                 del m
-        self.checked_params = leaves(
-            from_program(jax.device_get(self.trainer.state.params)),
-            self.layers)
+        self.checked_params = ref.leaves(
+            ref.from_program(jax.device_get(self.trainer.state.params),
+                             config), config)
 
     def step(self):
         k = len(self.losses)
@@ -260,20 +231,20 @@ class Training:
 
     def check(self):
         t0 = time.perf_counter()
-        ref = reference_module(self.config)
+        ref, config = self.ref, self.config
         seed = self.cell.seed
         mine = [batch_at(self.traffic, self.vocab, seed, k)
                 for k in range(self.checked)]
         differ = sum(int(np.sum(np.asarray(a) != b))
                      for got, want in zip(self.batches, mine)
                      for a, b in zip(got, want))
-        ref_losses, first, last = ref.train(self.config, self.opt, seed, mine)
+        ref_losses, first, last = ref.train(config, self.opt, seed, mine)
         t1 = time.perf_counter()
-        start = leaves(ref.init_params(self.config, seed), self.layers)
+        start = ref.leaves(ref.init_params(config, seed), config)
         got = compare(self.losses[:self.checked], ref_losses,
-                      leaf_norms(self.first_grad, leaves(first, self.layers)),
+                      leaf_norms(self.first_grad, ref.leaves(first, config)),
                       leaf_norms(self.checked_params,
-                                 leaves(last, self.layers), start))
+                                 ref.leaves(last, config), start))
         c = self.cell.counters
         c["leaves"], c["leaves_moved"] = got.pop("leaves"), got.pop(
             "leaves_moved")
@@ -318,20 +289,35 @@ def made_unit():
     Each 10 ms step runs the train-step program for 8 ms (1-9 ms of it);
     inside it a photonic_mac call of 3 ms (4,096 x 4,096 x 4,096), a causal
     flash-attention call of 2 ms (32 query and 4 key/value heads of 4,096
-    x 128) and 3 ms of fusions; a step is 7.88e11 model FLOPs."""
+    x 128), a forward ssm_scan call of 1 ms (its instruction as recorded
+    on a v5e in testdata/v5e_two_units.xplane.pb: 8 heads of 256 x 64) and
+    2 ms of fusions; a step is 7.88e11 model FLOPs."""
     mac = ("%photonic_mac.1 = f32[4096,4096]{1,0} custom-call("
            "bf16[4096,4096]{1,0} %x, s8[4096,4096]{1,0} %w, "
            "f32[32,32,1,1]{3,2,1,0} %s)")
     fa = ("%flash_attention.2 = f32[32,4096,128]{2,1,0} custom-call("
           "bf16[32,4096,128]{2,1,0} %q, bf16[4,4096,128]{2,1,0} %k, "
           "bf16[4,4096,128]{2,1,0} %v)")
+    scan = ("%ssm_scan.2 = f32[8,256,64]{2,1,0:T(8,128)S(1)} custom-call("
+            "bf16[8,256,64]{2,1,0:T(8,128)(2,1)S(1)} %copy.8, "
+            "f32[8,256,1]{2,1,0:T(8,128)S(1)} %copy.9, "
+            "f32[8,1,256]{2,1,0:T(1,128)S(1)} %bitcast.6, "
+            "f32[8,2,1,1]{3,2,1,0:T(1,128)S(1)} %copy.10, "
+            "bf16[8,256,64]{2,1,0:T(8,128)(2,1)S(1)} %copy.11, "
+            "bf16[8,256,64]{2,1,0:T(8,128)(2,1)S(1)} %copy.12), "
+            'custom_call_target="tpu_custom_call", '
+            "operand_layout_constraints={bf16[8,256,64]{2,1,0}, "
+            "f32[8,256,1]{2,1,0}, f32[8,1,256]{2,1,0}, "
+            "f32[8,2,1,1]{3,2,1,0}, bf16[8,256,64]{2,1,0}, "
+            "bf16[8,256,64]{2,1,0}}, frontend_attributes={kernel_metadata={}}")
     fusion = "%fusion.3 = f32[8]{0} fusion(f32[8]{0} %a)"
     mods, ops, spans = [], [], []
     for t in (0, 10 * MS):
         mods.append(Event("jit_step_fn(77)", t + 1 * MS, t + 9 * MS))
         ops += [Event(mac, t + 1 * MS, t + 4 * MS),
                 Event(fa, t + 4 * MS, t + 6 * MS),
-                Event(fusion, t + 6 * MS, t + 9 * MS)]
+                Event(scan, t + 6 * MS, t + 7 * MS),
+                Event(fusion, t + 7 * MS, t + 9 * MS)]
         spans.append(Event("bench.unit", t, t + 10 * MS))
     return TraceData({0: mods}, {0: ops}, spans), {
         "device_kind": "TPU v5 lite", "model_flops_per_step": 7.88e11}

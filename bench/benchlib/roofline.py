@@ -5,7 +5,8 @@ A roofline share is the least time the chip could take for the work, the
 larger of operations over peak FLOP/s and bytes over peak bytes/s, over the
 time the work took.  Operations are those the algorithm needs: a causal
 attention counts the query-key pairs at or below the diagonal, and
-recomputation in the backward pass is not counted.
+recomputation in the backward pass is not counted.  A whole model's FLOPs
+are counted by its configuration's reference (`model_flops_per_token`).
 """
 
 from __future__ import annotations
@@ -40,39 +41,6 @@ def nbytes(shape: Shape) -> int:
     for d in dims:
         n *= d
     return n
-
-
-def lm_sizes(config: dict) -> dict:
-    """The sizes of a dense GQA decoder, from a configuration file's keys
-    (those of the model's published config.json)."""
-    d = config["hidden_size"]
-    h = config["num_attention_heads"]
-    return {"layers": config["num_hidden_layers"], "d": d, "heads": h,
-            "kv_heads": config["num_key_value_heads"],
-            "head_dim": config.get("head_dim") or d // h,
-            "ffn": config["intermediate_size"], "vocab": config["vocab_size"]}
-
-
-def lm_matmul_params(config: dict) -> Tuple[int, int]:
-    """(matmul parameters of one layer, of the output head)."""
-    s = lm_sizes(config)
-    d, dh = s["d"], s["head_dim"]
-    attn = d * dh * (s["heads"] + 2 * s["kv_heads"]) + s["heads"] * dh * d
-    mlp = 3 * d * s["ffn"]
-    return attn + mlp, d * s["vocab"]
-
-
-def lm_train_flops_per_token(config: dict, seq_len: int,
-                             causal: bool = True) -> float:
-    """Model FLOPs of one training token, forward and backward: 6 x (layer
-    matmul parameters + output head), plus attention's two matmuls at 6 x
-    heads x head_dim x keys attended per layer (seq / 2 on average for a
-    causal mask, seq without one)."""
-    s = lm_sizes(config)
-    layer, head = lm_matmul_params(config)
-    keys = (seq_len + 1) / 2 if causal else seq_len
-    attn = 6 * s["layers"] * s["heads"] * s["head_dim"] * keys * 2
-    return 6.0 * (s["layers"] * layer + head) + attn
 
 
 def photonic_mac_cost(shapes: Sequence[Shape]) -> Tuple[float, float]:

@@ -2,7 +2,10 @@
 (Yi-6B: arXiv:2403.04652, https://huggingface.co/01-ai/Yi-6B): forward,
 loss, gradient and AdamW, in straightforward jax.numpy at the highest
 matmul precision.  It imports nothing of the program and uses no kernel,
-cache or batching.  It also makes the weights that both sides start from.
+cache or batching.  It also makes the weights that both sides start from,
+and is the one place that knows this configuration's weight layout (the
+program's tree, the leaves the check compares) and its model FLOPs
+(`bench/README.md` lists what a training cell's reference defines).
 
 The model, as the published config states it: token embedding; per layer
 x + Attn(RMSNorm(x)) then x + MLP(RMSNorm(x)); a final RMSNorm and an
@@ -58,6 +61,27 @@ def sizes(config: dict) -> dict:
             "bits": config["photonic_bits"]}
 
 
+def matmul_params(config: dict):
+    """(matmul parameters of one layer, of the output head)."""
+    s = sizes(config)
+    d, dh = s["d"], s["dh"]
+    attn = d * dh * (s["h"] + 2 * s["hk"]) + s["h"] * dh * d
+    return attn + 3 * d * s["f"], d * s["V"]
+
+
+def model_flops_per_token(config: dict, seq_len: int,
+                          causal: bool = True) -> float:
+    """Model FLOPs of one training token, forward and backward: 6 x (layer
+    matmul parameters + output head), plus attention's two matmuls at 6 x
+    heads x head_dim x keys attended per layer (seq / 2 on average for a
+    causal mask, seq without one).  Recomputation is not counted."""
+    s = sizes(config)
+    layer, head = matmul_params(config)
+    keys = (seq_len + 1) / 2 if causal else seq_len
+    attn = 6 * s["L"] * s["h"] * s["dh"] * keys * 2
+    return 6.0 * (s["L"] * layer + head) + attn
+
+
 def key_of(seed: int):
     """A key from any whole seed, more than 32 bits included."""
     return jax.random.fold_in(jax.random.PRNGKey(seed % 2**32), seed // 2**32)
@@ -98,6 +122,45 @@ def init_params(config: dict, seed: int) -> dict:
     default device: N(0, 1/fan_in) matrices (the embedding and the head
     1/hidden), norm scales 0."""
     return _init(tuple(sorted(leaf_shapes(config).items())), key_of(seed))
+
+
+def to_program(params: dict, config: dict) -> dict:
+    """The weights in the program's `Trainer` tree (no copies): one stage
+    whose `attn_0` block holds the layers stacked, and an untied head."""
+    p = params
+    return {"embed": p["embed"], "lm_head": p["lm_head"],
+            "final_norm": p["final_norm"],
+            "stages": [{"attn_0": {
+                "attn": {"wq": p["wq"], "wk": p["wk"], "wv": p["wv"],
+                         "wo": p["wo"], "norm": p["attn_norm"]},
+                "mlp": {"wi": p["wi"], "wg": p["wg"], "wo": p["wd"],
+                        "norm": p["mlp_norm"]}}}]}
+
+
+def from_program(tree: dict, config: dict) -> dict:
+    """The program's tree (weights, or an AdamW moment laid out as they
+    are) as this module's weights (no copies)."""
+    a = tree["stages"][0]["attn_0"]["attn"]
+    m = tree["stages"][0]["attn_0"]["mlp"]
+    return {"embed": tree["embed"], "lm_head": tree["lm_head"],
+            "final_norm": tree["final_norm"], "wq": a["wq"], "wk": a["wk"],
+            "wv": a["wv"], "wo": a["wo"], "attn_norm": a["norm"],
+            "wi": m["wi"], "wg": m["wg"], "wd": m["wo"],
+            "mlp_norm": m["norm"]}
+
+
+def leaves(params: dict, config: dict) -> dict:
+    """{leaf name: array} the check compares: each layer's slice of a
+    stacked weight a leaf of its own (`wq.0`, ...), and the embedding, the
+    head and the final norm whole."""
+    out = {}
+    for name, x in sorted(params.items()):
+        if name in LAYER_LEAVES:
+            for i in range(config["num_hidden_layers"]):
+                out[f"{name}.{i}"] = x[i]
+        else:
+            out[name] = x
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -215,6 +278,14 @@ def loss(params, tokens, labels, config, blocked: bool = True):
     logz = jax.scipy.special.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     return jnp.mean(logz - gold)
+
+
+def loss_fn(config: dict):
+    """The loss as a function of (params, tokens, labels), every layer in
+    turn and nothing recomputed: the step whose operations XLA counts
+    against `model_flops_per_token`."""
+    return lambda params, tokens, labels: loss(params, tokens, labels,
+                                               config, blocked=False)
 
 
 # ---------------------------------------------------------------------------

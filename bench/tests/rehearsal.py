@@ -31,6 +31,14 @@ def _small() -> dict:
 SMALL = _small()
 
 
+def workloads_driven_by(driver: str) -> list:
+    """The cells whose traffic file names `driver` ("module:Class"), in
+    BENCHMARK.json's order: the cases of a test of that cell class."""
+    return [w["name"] for w in benchmark()["workloads"]
+            if harness.load_json(BENCH / "traffic" / f"{w['traffic']}.json")
+            ["driver"] == driver]
+
+
 def overrides(workload: str) -> dict:
     return json.loads(json.dumps(SMALL[workload]))
 

@@ -95,6 +95,17 @@ def test_chip_trace_units_kernels_and_idle():
     assert b["idle_gaps"][0][1] == pytest.approx(0.02, rel=0.25)
 
 
+def test_made_training_step_runs_the_recorded_scan():
+    """The made training step's ssm_scan call is the instruction the chip
+    recorded, so a reader of the scan kernel finds it in both traces."""
+    from benchlib import lm
+    trace, _ = lm.made_unit()
+    made = traces.Window(trace).matching("ops", r"^ssm_scan$", 0)
+    recorded = _chip().matching("ops", r"^ssm_scan$", 0)
+    assert len(made) == 2
+    assert {e.name for e in made} == {e.name for e in recorded}
+
+
 @pytest.mark.parametrize("entry", benchmark()["per_layer"],
                          ids=lambda m: m["name"])
 def test_engine_readers_on_a_made_search(entry):
